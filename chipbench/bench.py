@@ -18,8 +18,10 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import importlib
 import importlib.util
 import json
+import re
 import shutil
 import statistics
 import time
@@ -43,10 +45,39 @@ def load_module(path: Path):
     return mod
 
 
+ARCH_NAME = re.compile(r"[a-z][a-z0-9_]*")
+
+
+def load_arch(name):
+    """The architecture module `arch/<name>.py` (its contract is in
+    `arch/dense.py`); a missing or unknown name is a KeyError."""
+    if not (isinstance(name, str) and ARCH_NAME.fullmatch(name)
+            and (HERE / "arch" / f"{name}.py").is_file()):
+        raise KeyError(f"no architecture {name!r} in chipbench/arch")
+    return importlib.import_module(f"chipbench.arch.{name}")
+
+
+def program_config(model_file: dict):
+    """The program's `ModelConfig` of a configuration file: its `model`
+    object, with a nested `moe` or `ssm` object made the program's
+    `MoEConfig` or `SSMConfig` and each JSON list a tuple."""
+    from repro.configs.base import ModelConfig, MoEConfig, SSMConfig
+    nested = {"moe": MoEConfig, "ssm": SSMConfig}
+    kw = {}
+    for k, v in model_file["model"].items():
+        if k in nested and isinstance(v, dict):
+            v = nested[k](**v)
+        elif isinstance(v, list):
+            v = tuple(v)
+        kw[k] = v
+    return ModelConfig(name=model_file["name"], **kw)
+
+
 def cell_spec(name: str, bench: dict) -> dict:
     """Everything a run of cell `name` needs, found by name: its entry
-    in BENCHMARK.json, workloads/<name>.json, configs/<config>.json,
-    traffic/<traffic>.json, and the metrics that apply to it."""
+    in BENCHMARK.json, workloads/<name>.json, configs/<config>.json and
+    the architecture module it names, traffic/<traffic>.json, and the
+    metrics that apply to it."""
     entry = next((w for w in bench["workloads"] if w["name"] == name), None)
     if entry is None:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
@@ -55,6 +86,7 @@ def cell_spec(name: str, bench: dict) -> dict:
                                   .read_text())
     spec["model_file"] = json.loads(
         (HERE / "configs" / f"{entry['config']}.json").read_text())
+    spec["arch"] = load_arch(spec["model_file"].get("arch"))
     spec["traffic_params"] = json.loads(
         (HERE / "traffic" / f"{entry['traffic']}.json").read_text())
 
@@ -83,9 +115,10 @@ class CompileCount:
 class Probe:
     """Spans, counters and records around the program's calls."""
 
-    def __init__(self, ctl, engine, model: dict, seed: int, opt: dict,
-                 streams=()):
-        self.ctl, self.engine, self.model = ctl, engine, model
+    def __init__(self, ctl, engine, arch, model: dict, seed: int,
+                 opt: dict, streams=()):
+        self.ctl, self.engine = ctl, engine
+        self.arch, self.model = arch, model
         self.seed, self.opt = seed, opt
         self.open: List[str] = []
         self.timing = False
@@ -149,7 +182,8 @@ class Probe:
             self.train_steps += 1
             if self.timing:
                 r, s = batch["inputs"].shape
-                self.model_flops += flops.train_flops(self.model, r, s)
+                self.model_flops += flops.train_flops(self.arch,
+                                                      self.model, r, s)
             if self.train["update"] is None:
                 self._on_train(state, batch, out)
             return out
@@ -170,8 +204,8 @@ class Probe:
         def acc(params, toks):
             if self.timing:
                 r, s = toks.shape
-                self.model_flops += flops.forward_flops(self.model,
-                                                              r, s)
+                self.model_flops += self.arch.forward_flops(self.model,
+                                                            r, s)
             return scalar(params, toks)
         eng._acc = eng._acc_prec["fp32"] = acc
 
@@ -184,7 +218,7 @@ class Probe:
                 def counted(params, toks):
                     if self.timing:
                         r, s = toks.shape
-                        self.model_flops += flops.forward_flops(
+                        self.model_flops += self.arch.forward_flops(
                             self.model, r, s)
                     return fn(params, toks)
                 wrapped_flat[fn] = counted
@@ -200,18 +234,22 @@ class Probe:
         def prefill_step(params, prompts):
             if self.timing:
                 r, s = prompts.shape
-                self.model_flops += flops.forward_flops(self.model,
-                                                              r, s)
+                self.model_flops += self.arch.forward_flops(self.model,
+                                                            r, s)
             return prefill(params, prompts)
         plane._prefill = prefill_step
-        decode = plane._fleet_decode
+        run = plane._fleet_decode_run
 
-        def decode_step(stack, rows, toks, cache, pos):
+        def decode_run(stack, rows, toks, pool, lanes, live, pos, k):
+            # counted from the launch's host arguments, outside the run's
+            # trace: `k` ticks of the `live` lanes from their positions
             if self.timing:
-                self.model_flops += flops.decode_flops(
-                    self.model, np.asarray(pos))
-            return decode(stack, rows, toks, cache, pos)
-        plane._fleet_decode = decode_step
+                start = np.asarray(pos)[:int(live)]
+                self.model_flops += sum(
+                    self.arch.decode_flops(self.model, start + j)
+                    for j in range(int(k)))
+            return run(stack, rows, toks, pool, lanes, live, pos, k)
+        plane._fleet_decode_run = decode_run
         enqueue, drain = plane.enqueue, plane.drain
 
         def enqueue_q(request_id, group_id, prompt):
@@ -261,7 +299,7 @@ class Probe:
                 lambda m: m / (1 - self.opt["b1"]), new["opt"]["mu"]))
         tr["chain"] = new
         if len(tr["batches"]) == 3:
-            p0 = weights.make_params(self.model, self.seed)
+            p0 = weights.make_params(self.arch, self.model, self.seed)
             tr["update"] = reference.leaf_norms(
                 jax.tree.map(lambda a, b: a - b, new["params"], p0))
             tr["chain"] = None
@@ -327,20 +365,20 @@ def run_cell(spec: dict, *, seed: int, seconds: float, trace: bool,
     wraps it (tests plant faults there, underneath the timed path);
     `device_kind` picks the peak table's row where the device is not the
     chip (tests on the CPU)."""
-    from repro.configs.base import ModelConfig
     from repro.core.controller import ControllerConfig, ECCOController
     from repro.core.trainer import SharedEngine
     from repro.serve.plane import ServeConfig
 
     st = spec["settings"]
     mf = spec["model_file"]
+    arch = spec["arch"]
     model = mf["model"]
     opt = mf["optimizer"]
     dev = jax.devices()[0]
     peak = flops.peaks(device_kind or dev.device_kind)
     setup: Dict[str, float] = {}
 
-    cfg = ModelConfig(name=mf["name"], **model)
+    cfg = program_config(mf)
     key = weights.key_from_seed(seed)
     t = time.perf_counter()
     tp = spec["traffic_params"]
@@ -353,14 +391,15 @@ def run_cell(spec: dict, *, seed: int, seconds: float, trace: bool,
     engine = SharedEngine(cfg)
     # every job starts from the benchmark's weights, made on the device
     # in one jitted call when the job is created
-    engine.fresh_state = lambda _seed=0: weights.make_state(model, seed)
+    engine.fresh_state = lambda _seed=0: weights.make_state(arch, model,
+                                                            seed)
     serve = st.get("serve")
     cc = ControllerConfig(**st["controller"],
                           serve=ServeConfig(**serve) if serve else None)
     ctl = ECCOController(engine, streams, cc, seed=seed)
     for hook in hooks:
         hook(ctl)
-    probe = Probe(ctl, engine, model, seed, opt, streams)
+    probe = Probe(ctl, engine, arch, model, seed, opt, streams)
     ctl.warmup()
     setup["build"] = time.perf_counter() - t
 
@@ -458,19 +497,19 @@ def run_cell(spec: dict, *, seed: int, seconds: float, trace: bool,
     gc.collect()
 
     t = time.perf_counter()
-    params0 = weights.make_params(model, seed)
+    params0 = weights.make_params(arch, model, seed)
     if not trained:
         # nothing trained: every job and serving snapshot should hold
         # the benchmark's weights, so the reference scores with those
         eval_params = dict.fromkeys(eval_ids, params0)
         serve_params = dict.fromkeys(groups, params0)
-    numbers = check.compare(model, opt, rec, params0, serve_params,
+    numbers = check.compare(arch, model, opt, rec, params0, serve_params,
                             eval_params, prompt_len, buckets)
     readings = None
     if mode == "readings":
-        readings = {m: check.compare(model, opt, rec, params0, serve_params,
-                                     eval_params, prompt_len, buckets,
-                                     mode=m)
+        readings = {m: check.compare(arch, model, opt, rec, params0,
+                                     serve_params, eval_params, prompt_len,
+                                     buckets, mode=m)
                     for m in ("control", "bf16", "half_batch", "altered")}
         readings["program"] = numbers
         # each control and fault judged by the cell's own limits

@@ -71,7 +71,7 @@ def eval_gap(accs, ref_accs) -> float:
     return float(np.max(np.abs(np.asarray(accs) - np.asarray(ref_accs))))
 
 
-def serve_gaps(model: dict, params, requests, prompt_len: int,
+def serve_gaps(arch, model: dict, params, requests, prompt_len: int,
                lowp: Optional[str] = None, alter: bool = False) -> float:
     """Widest gap of a served token below the reference's best logit.
     With `lowp`, the tokens scored are those the low-precision forward
@@ -79,11 +79,11 @@ def serve_gaps(model: dict, params, requests, prompt_len: int,
     replaced by its successor in the vocabulary."""
     toks = np.stack([np.concatenate([p, s[:-1]]) for p, s in requests])
     served = np.stack([s for _, s in requests])
-    lg = np.asarray(reference.logits(model, params, toks))
+    lg = np.asarray(reference.logits(arch, model, params, toks))
     pos = prompt_len - 1 + np.arange(served.shape[1])
     ref = lg[:, pos]                                   # (R, new, V)
     if lowp is not None:
-        low = np.asarray(reference.logits(model, params, toks, lowp))
+        low = np.asarray(reference.logits(arch, model, params, toks, lowp))
         served = low[:, pos].argmax(-1)
     elif alter:
         served = (served + 1) % model["vocab_size"]
@@ -91,10 +91,11 @@ def serve_gaps(model: dict, params, requests, prompt_len: int,
     return float(np.max(ref.max(-1) - picked))
 
 
-def compare(model: dict, opt: dict, rec: dict, params0, serve_params,
+def compare(arch, model: dict, opt: dict, rec: dict, params0, serve_params,
             eval_params, prompt_len: int, buckets: int,
             mode: Optional[str] = None) -> Dict[str, float]:
-    """The cell's numbers. `rec` holds what the timed path produced
+    """The cell's numbers, through the architecture module `arch`
+    (`chipbench/arch/`). `rec` holds what the timed path produced
     (see bench.Probe.record); mode None compares the program, "control"
     puts the reference at the next lower precision in its place,
     "half_batch" / "altered" put the reference with that fault there,
@@ -104,17 +105,17 @@ def compare(model: dict, opt: dict, rec: dict, params0, serve_params,
     out: Dict[str, float] = {}
     tr = rec.get("train")
     if tr and len(tr["batches"]) == 3:
-        ref = reference.train(model, opt, params0, tr["batches"])
+        ref = reference.train(arch, model, opt, params0, tr["batches"])
         if mode is None:
             got = tr
         elif mode == "control":
-            got = reference.train(model, opt, params0, tr["batches"],
+            got = reference.train(arch, model, opt, params0, tr["batches"],
                                   lowp="fp8")
         elif mode == "bf16":
-            got = reference.train(model, opt, params0, tr["batches"],
+            got = reference.train(arch, model, opt, params0, tr["batches"],
                                   lowp="bf16")
         elif mode == "half_batch":
-            got = reference.train(model, opt, params0,
+            got = reference.train(arch, model, opt, params0,
                                   [b[: len(b) // 2] for b in tr["batches"]])
         else:
             got = None
@@ -124,16 +125,17 @@ def compare(model: dict, opt: dict, rec: dict, params0, serve_params,
     if ev:
         ref_acc, got_acc = [], []
         for jid, toks, acc in ev:
-            r = reference.accuracy(model, eval_params[jid], toks[None])[0]
+            r = reference.accuracy(arch, model, eval_params[jid],
+                                   toks[None])[0]
             ref_acc.append(r)
             if mode == "control":
                 got_acc.append(reference.accuracy(
-                    model, eval_params[jid], toks[None], "bf16")[0])
+                    arch, model, eval_params[jid], toks[None], "bf16")[0])
             elif mode == "altered":
                 # labels off by one position: the prediction for token i
                 # scored against token i itself
-                lg = np.asarray(reference.logits(model, eval_params[jid],
-                                                 toks))
+                lg = np.asarray(reference.logits(
+                    arch, model, eval_params[jid], toks))
                 got_acc.append(float(np.mean(lg[:, :-1].argmax(-1)
                                              == toks[:, :-1])))
             else:
@@ -146,7 +148,7 @@ def compare(model: dict, opt: dict, rec: dict, params0, serve_params,
         for gid, prompt, served in sv:
             by_group.setdefault(gid, []).append((prompt, served))
         out["serve_logit_gap"] = max(
-            serve_gaps(model, serve_params[g], reqs, prompt_len,
+            serve_gaps(arch, model, serve_params[g], reqs, prompt_len,
                        lowp="fp8" if mode == "control" else None,
                        alter=mode == "altered")
             for g, reqs in by_group.items())

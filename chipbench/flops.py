@@ -1,11 +1,10 @@
-"""Operations and bytes of the timed path's programs, from shapes.
+"""Peaks, operations and bytes of the timed path's programs, from shapes.
 
-Model FLOPs count the multiply-adds the architecture needs (two
-operations each): the projections, the feed-forward, the output head
-over the real vocabulary, and the attention scores and mixing over the
-keys each position may see. Training is three forward passes' worth
-(the program does not recompute). Nothing here is read from a compiled
-program's cost analysis.
+Model FLOPs are counted by the cell's architecture module
+(`chipbench/arch/<arch>.py`): the multiply-adds the architecture needs
+(two operations each) in a forward pass. Training is three forward
+passes' worth (the program does not recompute). Nothing here is read
+from a compiled program's cost analysis.
 """
 from __future__ import annotations
 
@@ -24,37 +23,9 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def matmul_params(model: dict) -> int:
-    """Weights that every token multiplies once in a forward pass."""
-    d, f = model["d_model"], model["d_ff"]
-    H, K = model["num_heads"], model["num_kv_heads"]
-    hd = model.get("head_dim") or d // H
-    per_layer = d * H * hd + 2 * d * K * hd + H * hd * d + 3 * d * f
-    return model["num_layers"] * per_layer + d * model["vocab_size"]
-
-
-def attention_flops(model: dict, keys_seen: int) -> int:
-    """Scores and mixing, summed over the keys all queries see."""
-    H = model["num_heads"]
-    hd = model.get("head_dim") or model["d_model"] // H
-    return model["num_layers"] * 4 * H * hd * keys_seen
-
-
-def forward_flops(model: dict, rows: int, seq: int) -> int:
-    """A causal forward pass over `rows` sequences of `seq` tokens."""
-    causal_keys = seq * (seq + 1) // 2
-    return rows * (2 * matmul_params(model) * seq
-                   + attention_flops(model, causal_keys))
-
-
-def train_flops(model: dict, rows: int, seq: int) -> int:
-    return 3 * forward_flops(model, rows, seq)
-
-
-def decode_flops(model: dict, positions) -> int:
-    """One token per lane, lane i at absolute position positions[i]."""
-    return sum(2 * matmul_params(model) + attention_flops(model, int(p) + 1)
-               for p in positions)
+def train_flops(arch, model: dict, rows: int, seq: int) -> int:
+    """A training step over `rows` sequences of `seq` tokens."""
+    return 3 * arch.forward_flops(model, rows, seq)
 
 
 def fleet_drift_cost(rows: int, tokens: int, buckets: int) -> dict:

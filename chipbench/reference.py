@@ -1,16 +1,17 @@
 """Plain reference of what the timed path computes.
 
 Imports nothing of the program. It reads parameter trees in the
-program's layout (see `weights.shapes`) and the token arrays the timed
-path was given, and computes in float32 with every matrix product at
-`Precision.HIGHEST`:
+program's layout and the token arrays the timed path was given, and
+computes in float32 with every matrix product at `Precision.HIGHEST`.
+The model's forward pass is its architecture module's
+(`chipbench/arch/<arch>.py`, passed in as `arch`), built from the
+helpers here; this module adds what every architecture shares:
 
-* the decoder forward pass (pre-norm blocks: LayerNorm with or without
-  affine, rotary attention over the first half/second half split,
-  causal softmax, SwiGLU, tied or untied output head over the vocabulary);
-* the training loss (next-token cross entropy plus 1e-4 times the mean
-  squared log-partition) and one AdamW step with global-norm clipping
-  and the warmup-cosine learning rate;
+* the training loss (next-token cross entropy, plus 1e-4 times the
+  mean squared log-partition, plus the optimizer's `aux_loss` times the
+  architecture's auxiliary loss) and one AdamW step with global-norm
+  clipping and the warmup-cosine learning rate;
+* top-1 accuracy and logits of the forward pass;
 * Jensen-Shannon drift scores of bucketed token histograms, in float64.
 
 `lowp` names the precision a control computes in: "bf16" casts every
@@ -24,13 +25,12 @@ training step computed in that precision would.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.weights import hashable
+from chipbench.weights import hashable, thaw
 
 F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
@@ -99,50 +99,17 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def forward(model: dict, params, tokens, lowp=None):
-    """Logits (B, S, vocab) over the real vocabulary, float32."""
-    kind = model["norm"]
-    table = params["embed"]["table"]
-    x = _round(table[tokens], lowp)
-    S = tokens.shape[1]
-    causal = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
-    seg = params["segments"][0]
-    for layer in range(model["num_layers"]):
-        lp = jax.tree.map(lambda a: a[layer], seg)
-        at = lp["attn"]
-        h = _round(_norm(kind, lp["ln1"], x), lowp)
-        q = _mm("bsd,dhk->bshk", h, at["wq"], lowp)
-        k = _mm("bsd,dhk->bshk", h, at["wk"], lowp)
-        v = _mm("bsd,dhk->bshk", h, at["wv"], lowp)
-        q = _round(_rope(q, model["rope_theta"]), lowp)
-        k = _round(_rope(k, model["rope_theta"]), lowp)
-        rep = q.shape[2] // k.shape[2]
-        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
-        s = _mm("bshd,bthd->bhst", q, k, lowp) / math.sqrt(q.shape[-1])
-        s = jnp.where(causal[None, None], s, NEG)
-        o = _mm("bhst,bthd->bshd", jax.nn.softmax(s, -1), v, lowp)
-        x = _round(x + _mm("bshk,hkd->bsd", o, at["wo"], lowp), lowp)
-        h2 = _round(_norm(kind, lp["ln2"], x), lowp)
-        g = _mm("bsd,df->bsf", h2, lp["mlp"]["w_gate"], lowp)
-        u = _mm("bsd,df->bsf", h2, lp["mlp"]["w_up"], lowp)
-        hh = _round(jax.nn.silu(g) * u, lowp)
-        x = _round(x + _mm("bsf,fd->bsd", hh, lp["mlp"]["w_down"], lowp),
-                   lowp)
-    x = _round(_norm(kind, params["final_norm"], x), lowp)
-    if model["tie_embeddings"]:
-        logits = _mm("bsd,vd->bsv", x, table, lowp)
-    else:
-        logits = _mm("bsd,dv->bsv", x, params["embed"]["unembed"], lowp)
-    return logits[..., :model["vocab_size"]]
-
-
-def loss(model: dict, params, tokens, lowp=None, z_weight=1e-4):
-    """Next-token cross entropy plus the z-loss, as the program trains."""
-    lg = forward(model, params, tokens, lowp)[:, :-1]
+def loss(arch, model: dict, opt: dict, params, tokens, lowp=None):
+    """Next-token cross entropy plus the z-loss and the weighted
+    auxiliary loss, as the program trains."""
+    lg, aux = arch.forward(model, params, tokens, lowp)
+    lg = lg[:, :-1]
     lab = tokens[:, 1:]
     lse = jax.nn.logsumexp(lg, -1)
     picked = jnp.take_along_axis(lg, lab[..., None], -1)[..., 0]
-    return jnp.mean(lse - picked) + z_weight * jnp.mean(jnp.square(lse))
+    return (jnp.mean(lse - picked)
+            + opt["z_loss"] * jnp.mean(jnp.square(lse))
+            + opt.get("aux_loss", 0.0) * aux)
 
 
 def adamw(opt: dict, params, grads, state):
@@ -173,12 +140,12 @@ def adamw(opt: dict, params, grads, state):
     return new, {"mu": mu, "nu": nu, "count": count}
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _train_step(model_items, opt_items, lowp, params, state, tokens):
-    model, opt = dict(model_items), dict(opt_items)
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _train_step(arch, model_items, opt_items, lowp, params, state, tokens):
+    model, opt = thaw(model_items), thaw(opt_items)
     with jax.default_matmul_precision("highest"):
         val, grads = jax.value_and_grad(
-            lambda p: loss(model, p, tokens, lowp, opt["z_loss"]))(params)
+            lambda p: loss(arch, model, opt, p, tokens, lowp))(params)
         new, state = adamw(opt, params, grads, state)
     return new, state, val
 
@@ -196,7 +163,8 @@ def leaf_norms(tree) -> dict:
             for (p, _), n in zip(flat, norms)}
 
 
-def train(model: dict, opt: dict, params0, batches, lowp=None) -> dict:
+def train(arch, model: dict, opt: dict, params0, batches,
+          lowp=None) -> dict:
     """The first len(batches) steps from params0 and a zero AdamW state.
     Returns each step's loss, the first clipped gradient's leaf norms
     (from the first moment after one step) and the leaf norms of the
@@ -207,7 +175,7 @@ def train(model: dict, opt: dict, params0, batches, lowp=None) -> dict:
              "count": jnp.zeros((), jnp.int32)}
     params, losses, grad_norms = params0, [], None
     for i, toks in enumerate(batches):
-        params, state, val = _train_step(mi, oi, lowp, params, state,
+        params, state, val = _train_step(arch, mi, oi, lowp, params, state,
                                          jnp.asarray(toks, jnp.int32))
         losses.append(float(val))
         if i == 0:
@@ -217,24 +185,26 @@ def train(model: dict, opt: dict, params0, batches, lowp=None) -> dict:
     return {"loss": losses, "grad": grad_norms, "update": delta}
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _logits(model_items, lowp, params, tokens):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _logits(arch, model_items, lowp, params, tokens):
     with jax.default_matmul_precision("highest"):
-        return forward(dict(model_items), params, tokens, lowp)
+        return arch.forward(thaw(model_items), params, tokens, lowp)[0]
 
 
-def logits(model: dict, params, tokens, lowp=None):
-    return _logits(hashable(model), lowp, params, jnp.asarray(tokens,
-                                                            jnp.int32))
+def logits(arch, model: dict, params, tokens, lowp=None):
+    return _logits(arch, hashable(model), lowp, params,
+                   jnp.asarray(tokens, jnp.int32))
 
 
-def accuracy(model: dict, params, tokens, lowp=None) -> np.ndarray:
+def accuracy(arch, model: dict, params, tokens,
+             lowp=None) -> np.ndarray:
     """Top-1 next-token accuracy of each (B, S) batch in `tokens`
     (shape (P, B, S)): the share of positions whose largest logit is the
     next token."""
     toks = np.asarray(tokens)
     P, B, S = toks.shape
-    lg = np.asarray(logits(model, params, toks.reshape(P * B, S), lowp))
+    lg = np.asarray(logits(arch, model, params, toks.reshape(P * B, S),
+                           lowp))
     hit = lg[:, :-1].argmax(-1) == toks.reshape(P * B, S)[:, 1:]
     return hit.reshape(P, B * (S - 1)).mean(-1)
 

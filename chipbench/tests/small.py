@@ -1,6 +1,8 @@
 """A cell at a size the CPU runs in seconds, for the tests."""
 import copy
 
+from chipbench.arch import dense
+
 MODEL = {"family": "dense", "num_layers": 1, "d_model": 64, "num_heads": 4,
          "num_kv_heads": 4, "d_ff": 128, "vocab_size": 256,
          "norm": "layernorm", "act": "swiglu", "rope_theta": 10000.0,
@@ -37,8 +39,9 @@ def spec(serve=True):
         settings["limits"].pop("serve_logit_gap")
     return {"name": "small", "config": "small", "traffic": "small",
             "chips": 1, "settings": settings,
-            "model_file": {"name": "small", "model": dict(MODEL),
-                           "optimizer": dict(OPT)},
+            "arch": dense,
+            "model_file": {"name": "small", "arch": "dense",
+                           "model": dict(MODEL), "optimizer": dict(OPT)},
             "traffic_params": dict(TRAFFIC),
             "end_to_end": [{"name": "window_s", "unit": "s"},
                            {"name": "setup_s", "unit": "s"}],

@@ -2,6 +2,7 @@
 import pytest
 
 from chipbench import flops
+from chipbench.arch import dense
 
 # d 64, 4 heads of 16, d_ff 128, vocab 256, one layer
 SMALL = {"num_layers": 1, "d_model": 64, "num_heads": 4, "num_kv_heads": 4,
@@ -10,7 +11,7 @@ SMALL = {"num_layers": 1, "d_model": 64, "num_heads": 4, "num_kv_heads": 4,
 
 def test_matmul_params_by_hand():
     # q, k, v, o: 4 * 64 * 64; SwiGLU: 3 * 64 * 128; head: 64 * 256
-    assert flops.matmul_params(SMALL) == 4 * 4096 + 3 * 8192 + 16384
+    assert dense.matmul_params(SMALL) == 4 * 4096 + 3 * 8192 + 16384
 
 
 def test_forward_train_and_decode_by_hand():
@@ -18,9 +19,9 @@ def test_forward_train_and_decode_by_hand():
     # 2 rows of 3 tokens: 2 * 3 * 2p, plus attention over 1 + 2 + 3 keys
     # at 4 * heads * head_dim per key
     attn = 4 * 4 * 16 * (1 + 2 + 3)
-    assert flops.forward_flops(SMALL, 2, 3) == 2 * (2 * p * 3 + attn)
-    assert flops.train_flops(SMALL, 2, 3) == 3 * 2 * (2 * p * 3 + attn)
-    assert flops.decode_flops(SMALL, [0, 4]) == \
+    assert dense.forward_flops(SMALL, 2, 3) == 2 * (2 * p * 3 + attn)
+    assert flops.train_flops(dense, SMALL, 2, 3) == 3 * 2 * (2 * p * 3 + attn)
+    assert dense.decode_flops(SMALL, [0, 4]) == \
         2 * (2 * p) + 4 * 4 * 16 * (1 + 5)
 
 

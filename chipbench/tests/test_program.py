@@ -144,7 +144,10 @@ def test_rescore_share_reads_the_drift_counters(snap):
 def test_readers_return_none_on_a_program_without_tracing(name,
                                                           monkeypatch):
     tr, _ = make()
-    monkeypatch.setitem(sys.modules, "repro.obs", None)  # import fails
+    # the import fails, whether or not an earlier test imported it:
+    # `from repro import obs` reads the package's attribute first
+    monkeypatch.setitem(sys.modules, "repro.obs", None)
+    monkeypatch.delattr("repro.obs", raising=False)
     assert program.snapshot() is None
     assert read(name, reading(tr)) is None
     untraced = reading(tr)
